@@ -391,6 +391,8 @@ def main() -> None:
                     help="comma-separated bench names to run (e.g. "
                          "'kernel_dispatch' for the CI kernel artifact)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     benches = {
         "basecaller": bench_basecaller,

@@ -166,22 +166,6 @@ def lane_mesh(n_devices: Optional[int] = None) -> Mesh:
     return Mesh(np.asarray(devs[:n]), (LANE_AXIS,))
 
 
-def shard_map_compat(fn, mesh: Mesh, *, in_specs, out_specs):
-    """``jax.shard_map`` across the jax versions this repo supports.
-
-    jax >= 0.5 exposes it as ``jax.shard_map``; earlier versions only have
-    ``jax.experimental.shard_map.shard_map`` (whose replication checker
-    rejects the debug callbacks the compute fabric uses for counters, so
-    ``check_rep=False``).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
-
-
 def param_shardings(axes_tree, shape_tree):
     """NamedSharding tree for a params pytree (shape_tree from eval_shape)."""
     ctx = _CTX

@@ -200,9 +200,7 @@ def scale_rule(rule: Optional[Segments], payload_ndim: int
 
 # ========================================================== plan builder ==
 def _flatten_with_keys(tree, is_leaf=None):
-    flatten_with_path = getattr(jax.tree, "flatten_with_path",
-                                jax.tree_util.tree_flatten_with_path)
-    flat, treedef = flatten_with_path(tree, is_leaf=is_leaf)
+    flat, treedef = jax.tree.flatten_with_path(tree, is_leaf=is_leaf)
     items = []
     for path, leaf in flat:
         names = [str(getattr(p, "key", getattr(p, "idx", p))) for p in path]

@@ -136,8 +136,9 @@ class LMDecodeEngine(EngineBase):
             name: P(*("model" if i == _CACHE_TP_DIM[name] % leaf.ndim
                       else None for i in range(leaf.ndim)))
             for name, leaf in cache_like.items()}
-        self.cache = jax.jit(shardlib.shard_map_compat(
-            _local_cache, mesh, in_specs=(), out_specs=cache_specs))()
+        self.cache = jax.jit(jax.shard_map(
+            _local_cache, mesh=mesh, in_specs=(), out_specs=cache_specs,
+            check_vma=False))()
 
         param_specs = tp_mod.param_pspecs(plan, params)
 
@@ -146,10 +147,10 @@ class LMDecodeEngine(EngineBase):
                     tp_mod.axis_ctx("model", ext):
                 return model.serve(p, c, t, pos, cfg)
 
-        self._step = jax.jit(shardlib.shard_map_compat(
-            _serve, mesh,
+        self._step = jax.jit(jax.shard_map(
+            _serve, mesh=mesh,
             in_specs=(param_specs, cache_specs, P(), P()),
-            out_specs=(P(), cache_specs)))
+            out_specs=(P(), cache_specs), check_vma=False))
 
     @property
     def slots(self) -> int:

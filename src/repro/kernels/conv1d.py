@@ -9,8 +9,10 @@ O(input) (no materialized im2col buffer).
 Blocking:
   grid = (B, T_out/bt, C_out/bn); each step loads the input rows
   [i*bt*stride, i*bt*stride + (bt-1)*stride + K) as a main block plus its
-  right neighbour (halo), and the full (K, Cin, bn) weight slab.  For the
-  paper's basecaller (Cin <= 512, K <= 11) the slab is < 3 MB of VMEM.
+  right neighbour (halo), and the full (K, Cin, bn) weight slab.  Both
+  blocks are staged in one 32-bit VMEM scratch and each of the K taps is a
+  strided ref load from it.  For the paper's basecaller (Cin <= 512,
+  K <= 11) the slab is < 3 MB of VMEM.
 """
 from __future__ import annotations
 
@@ -21,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels import compat
 
 from repro.kernels.matmul import _ACTIVATIONS
 
@@ -43,16 +43,21 @@ def stream_carry_len(ksize: int, stride: int) -> int:
     return ksize - stride
 
 
-def _conv1d_kernel(x_ref, xn_ref, w_ref, bias_ref, o_ref, *, ksize: int,
-                   stride: int, activation: str, block_t: int, acc_dtype):
-    # x_ref:  (1, block_t*stride, Cin)  rows starting at i*block_t*stride
-    # xn_ref: (1, block_t*stride, Cin)  the next block (halo source)
-    x = jnp.concatenate([x_ref[0], xn_ref[0]], axis=0)
+def _conv1d_kernel(x_ref, xn_ref, w_ref, bias_ref, o_ref, buf_ref, *,
+                   ksize: int, stride: int, activation: str, block_t: int,
+                   acc_dtype):
+    # x_ref:   (1, block_t*stride, Cin)  rows starting at i*block_t*stride
+    # xn_ref:  (1, block_t*stride, Cin)  the next block (halo source)
+    # buf_ref: (2*block_t*stride, Cin)   32-bit VMEM staging of both blocks,
+    #          so each tap is a strided *ref* load (Mosaic has no strided
+    #          value slice, and strided loads only of 32-bit data)
+    span = x_ref.shape[1]
+    buf_ref[:span, :] = x_ref[0].astype(buf_ref.dtype)
+    buf_ref[span:, :] = xn_ref[0].astype(buf_ref.dtype)
     acc = None
     for k in range(ksize):
         # rows k, k+stride, ..., k+(block_t-1)*stride
-        xk = jax.lax.slice(x, (k, 0), (k + (block_t - 1) * stride + 1, x.shape[1]),
-                           (stride, 1))
+        xk = buf_ref[pl.ds(k, block_t, stride=stride), :].astype(x_ref.dtype)
         part = jnp.dot(xk, w_ref[k], preferred_element_type=acc_dtype)
         acc = part if acc is None else acc + part
     if bias_ref is not None:
@@ -114,9 +119,9 @@ def conv1d(
                                    activation=activation, block_t=block_t,
                                    acc_dtype=acc_dtype)
     else:
-        def kernel(x_ref, xn_ref, w_ref, o_ref):
-            _conv1d_kernel(x_ref, xn_ref, w_ref, None, o_ref, ksize=ksize,
-                           stride=stride, activation=activation,
+        def kernel(x_ref, xn_ref, w_ref, o_ref, buf_ref):
+            _conv1d_kernel(x_ref, xn_ref, w_ref, None, o_ref, buf_ref,
+                           ksize=ksize, stride=stride, activation=activation,
                            block_t=block_t, acc_dtype=acc_dtype)
 
     return pl.pallas_call(
@@ -125,7 +130,9 @@ def conv1d(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_t, block_n), lambda b, i, j: (b, i, j)),
         out_shape=jax.ShapeDtypeStruct((bsz, t_out, cout), out_dtype),
-        compiler_params=compat.CompilerParams(
+        scratch_shapes=[pltpu.VMEM((2 * span, cin), jnp.int32 if int_inputs
+                                   else jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=interpret,

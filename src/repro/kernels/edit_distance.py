@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 _BIG = 2**20
 
 
@@ -55,7 +53,7 @@ def _wavefront_kernel(q_ref, t_ref, o_ref, prev2_ref, prev_ref, tdiag_ref,
         prev = prev_ref[...]
         prev2 = prev2_ref[...]
         # shift target chars down the diagonal; row 0 takes target[t-1]
-        t_new = jax.lax.dynamic_slice(t_ref[...], (t - 1, 0), (1, bp))
+        t_new = t_ref[pl.ds(t - 1, 1), :]
         tdiag = jnp.concatenate([t_new, tdiag_ref[: m]], axis=0)
         tdiag_ref[...] = tdiag
 
@@ -97,7 +95,7 @@ def _wavefront_kernel(q_ref, t_ref, o_ref, prev2_ref, prev_ref, tdiag_ref,
     if local:
         o_ref[...] = best_ref[...]
     else:
-        o_ref[...] = jax.lax.dynamic_slice(prev_ref[...], (m, 0), (1, bp))
+        o_ref[...] = prev_ref[m:m + 1, :]
 
 
 def _wavefront(query, target, *, local, band, match, mismatch, gap, block_p,
@@ -127,7 +125,7 @@ def _wavefront(query, target, *, local, band, match, mismatch, gap, block_p,
             pltpu.VMEM((m + 1, block_p), jnp.int32),
             pltpu.VMEM((1, block_p), jnp.int32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 
 _NEG_INF = -1e30
 _STATS = 128  # stat buffers keep a full lane dim; column 0 is authoritative
@@ -123,7 +122,7 @@ def flash_attention(
             pltpu.VMEM((block_q, _STATS), jnp.float32),
             pltpu.VMEM((block_q, _STATS), jnp.float32),
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

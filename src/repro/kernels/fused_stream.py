@@ -46,8 +46,6 @@ Fallback taxonomy (counted ``fabric.fallback.fused_stream.<reason>``):
                        lane-blocked program cannot take)
   ``precision_policy`` a tuned ``precision="int8"`` bucket on float weights
                        (per-call weight requant stays on the unfused path)
-  ``tpu_channel_align`` compiled-mode lane-tile floors (cout < 128) on a
-                       real TPU backend; interpret mode has no such floor
 """
 from __future__ import annotations
 
@@ -56,9 +54,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import ctc
-from repro.kernels import compat
 from repro.kernels import fabric
 from repro.kernels import fabric as _fabric_mod
 from repro.kernels import ops as _ops
@@ -185,98 +183,158 @@ def _fused_reference(rows, pads, reset, prev, bases, ticks, conv, params, *,
 
 
 # ========================================================== pallas target ==
+def _quantize_tap(tap, sa):
+    # static-act-scale quantization, exactly qcore.quantize: the same
+    # round/clip the unfused int8 path applies per layer (elementwise, so
+    # quantizing a strided tap == striding the quantized buffer)
+    return jnp.clip(jnp.round(tap / sa), -QMAX, QMAX).astype(jnp.int8)
+
+
 def _fused_kernel(refs, *, meta, block_l, chunk, n_frames):
     """The persistent program body for one block of lanes.
 
-    ``refs`` is the flat (inputs..., outputs...) ref list; ``meta`` is the
-    static per-layer plan built by :func:`_fused_pallas`."""
+    ``refs`` is the flat (inputs..., outputs..., scratch...) ref list;
+    ``meta`` is the static per-layer plan built by :func:`_fused_pallas`.
+
+    Layout.  Hidden layers run lane-batched with time on sublanes and
+    channels on lanes, ``(block_l, T, C)``; each layer's ``[carry, x]``
+    input is staged in a VMEM scratch so every conv tap is a strided *ref*
+    load (Mosaic lowers no strided value slice).  The last layer runs per
+    lane *transposed*, ``(C, F)`` with frames on lanes, so the per-frame
+    argmax, CTC collapse and token rows need no sublane<->lane relayout; the
+    prefix sum and the one-frame shift of the collapse are exact 0/1 GEMMs.
+    """
     it = iter(refs)
     rows_ref = next(it)
     pads_ref = next(it)
     reset_ref = next(it)
+    reset3_ref = next(it)
     prev_ref = next(it)
     bases_ref = next(it)
     ticks_ref = next(it)
-    carry_in = {}
-    w_refs = {}
+    carry_in, w_refs = {}, {}
     for m in meta:
         if m["carry_rows"]:
             carry_in[m["i"]] = next(it)
-        if m["quantized"]:
-            w_refs[m["i"]] = (next(it), next(it), next(it), next(it))
-        else:
-            w_refs[m["i"]] = (next(it), next(it))
+        w_refs[m["i"]] = tuple(next(it) for _ in
+                               range(4 if m["quantized"] else 2))
     tokens_ref = next(it)
     lens_ref = next(it)
     prev_out_ref = next(it)
     bases_out_ref = next(it)
     ticks_out_ref = next(it)
     carry_out = {m["i"]: next(it) for m in meta if m["carry_rows"]}
+    stage = {m["i"]: next(it) for m in meta if m["staged"]}
+    best_ref = next(it)
 
     rmask = reset_ref[...] > 0.0                       # (bl, 1)
-    x = rows_ref[...].astype(jnp.float32)[..., None]   # (bl, T, 1)
+    x = rows_ref[...].astype(jnp.float32)              # (bl, T, 1)
+    last = meta[-1]
     for m in meta:
         i, ksize, stride = m["i"], m["ksize"], m["stride"]
         t_in = x.shape[1]
-        if m["carry_rows"]:
-            carry = jnp.where(rmask[:, :, None], 0.0, carry_in[i][...])
-            buf = jnp.concatenate([carry, x], axis=1)
-            carry_out[i][...] = buf[:, buf.shape[1] - m["carry_rows"]:, :]
-        else:
-            buf = x
         t_out = t_in // stride
+        cr = m["carry_rows"]
+        if m["staged"]:
+            scr = stage[i]                             # (bl, cr + T, cin)
+            if cr:
+                carry = jnp.where(reset3_ref[...] > 0.0, 0.0,
+                                  carry_in[i][...])
+                scr[:, :cr, :] = carry
+            scr[:, cr:, :] = x
+            if cr:
+                carry_out[i][...] = scr[:, t_in:, :]
+        if m is last:
+            break
+        cin = x.shape[2]
         if m["quantized"]:
             wq_ref, scale_ref, bias_ref, sa_ref = w_refs[i]
-            # static-act-scale quantization, exactly qcore.quantize: the
-            # same round/clip the unfused int8 path applies per layer
             sa = sa_ref[0, 0]
-            q = jnp.clip(jnp.round(buf / sa), -QMAX, QMAX).astype(jnp.int8)
-            acc = None
-            for k in range(ksize):
-                qk = jax.lax.slice(
-                    q, (0, k, 0),
-                    (block_l, k + (t_out - 1) * stride + 1, q.shape[2]),
-                    (1, stride, 1))
-                part = jax.lax.dot_general(
-                    qk, wq_ref[k], (((2,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32)
-                acc = part if acc is None else acc + part
-            # ops._int8_epilogue arithmetic, term for term
-            out = acc.astype(jnp.float32) * scale_ref[...]
-            out = out + bias_ref[...].astype(out.dtype)
-            x = _ACTIVATIONS[m["activation"]](out).astype(jnp.float32)
         else:
             w_ref, bias_ref = w_refs[i]
-            acc = None
-            for k in range(ksize):
-                xk = jax.lax.slice(
-                    buf, (0, k, 0),
-                    (block_l, k + (t_out - 1) * stride + 1, buf.shape[2]),
-                    (1, stride, 1))
+        acc = None
+        for k in range(ksize):
+            tap = (scr[:, pl.ds(k, t_out, stride=stride), :] if m["staged"]
+                   else x).reshape(block_l * t_out, cin)
+            if m["quantized"]:
+                part = jnp.dot(_quantize_tap(tap, sa), wq_ref[k],
+                               preferred_element_type=jnp.int32)
+            else:
+                part = jnp.dot(tap, w_ref[k],
+                               preferred_element_type=jnp.float32)
+            acc = part if acc is None else acc + part
+        if m["quantized"]:
+            # ops._int8_epilogue arithmetic, term for term
+            out = acc.astype(jnp.float32) * scale_ref[...]
+        else:
+            out = acc
+        out = out + bias_ref[...].astype(out.dtype)
+        x = _ACTIVATIONS[m["activation"]](out).astype(jnp.float32)
+        x = x.reshape(block_l, t_out, m["cout"])
+
+    # -------- last layer, per lane and transposed: logits (C, F) ---------
+    i, ksize, stride = last["i"], last["ksize"], last["stride"]
+    if last["quantized"]:
+        wq_ref, scale_ref, bias_ref, sa_ref = w_refs[i]  # (K, C, cin) ...
+        sa = sa_ref[0, 0]
+    else:
+        w_ref, bias_ref = w_refs[i]                      # (K, C, cin), (C, 1)
+    nt = (((1,), (1,)), ((), ()))                        # (C,cin)x(F,cin)^T
+    for lane in range(block_l):
+        acc = None
+        for k in range(ksize):
+            tap = (stage[i][lane, pl.ds(k, n_frames, stride=stride), :]
+                   if last["staged"] else x[lane])       # (F, cin)
+            if last["quantized"]:
                 part = jax.lax.dot_general(
-                    xk, w_ref[k], (((2,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                acc = part if acc is None else acc + part
-            acc = acc + bias_ref[...].astype(acc.dtype)
-            x = _ACTIVATIONS[m["activation"]](acc).astype(jnp.float32)
+                    wq_ref[k], _quantize_tap(tap, sa), nt,
+                    preferred_element_type=jnp.int32)
+            else:
+                part = jax.lax.dot_general(
+                    w_ref[k], tap, nt, preferred_element_type=jnp.float32)
+            acc = part if acc is None else acc + part
+        out = (acc.astype(jnp.float32) * scale_ref[...] if last["quantized"]
+               else acc)
+        logits = _ACTIVATIONS[last["activation"]](
+            out + bias_ref[...].astype(out.dtype))       # (C, F)
+        # argmax over classes, first maximum wins (== jnp.argmax)
+        best = jnp.zeros((1, n_frames), jnp.int32)
+        top = logits[0:1, :]
+        for c in range(1, last["cout"]):
+            row = logits[c:c + 1, :]
+            better = row > top
+            best = jnp.where(better, c, best)
+            top = jnp.where(better, row, top)
+        best_ref[lane:lane + 1, :] = best
 
     # -------- incremental CTC collapse, lane-resident (== ctc.collapse) --
-    logits = x                                          # (bl, F, C)
-    best = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    best = jnp.where(pads_ref[...] > 0, ctc.BLANK, best)
+    best = jnp.where(pads_ref[...] > 0, ctc.BLANK, best_ref[...])  # (bl, F)
     prev0 = jnp.where(rmask, ctc.BLANK, prev_ref[...])  # (bl, 1)
-    prevs = jnp.concatenate([prev0, best[:, :n_frames - 1]], axis=1)
+    r = jax.lax.broadcasted_iota(jnp.int32, (n_frames, n_frames), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (n_frames, n_frames), 1)
+    # 0/1 GEMMs over small integers are exact at any MXU precision:
+    # shifted[:, j] = best[:, j-1];  pos[:, j] = sum_{i<=j} keep[:, i] - 1
+    shift = (c == r + 1).astype(jnp.float32)
+    upper = (r <= c).astype(jnp.float32)
+    col = jax.lax.broadcasted_iota(jnp.int32, best.shape, 1)
+    shifted = jnp.dot(best.astype(jnp.float32), shift,
+                      preferred_element_type=jnp.float32).astype(jnp.int32)
+    prevs = jnp.where(col == 0, prev0, shifted)
     keep = (best != ctc.BLANK) & (best != prevs)
+    keep_f = keep.astype(jnp.float32)
     lens = jnp.sum(keep.astype(jnp.int32), axis=1, keepdims=True)
-    pos = jnp.cumsum(keep.astype(jnp.int32), axis=1) - 1
+    pos = jnp.dot(keep_f, upper,
+                  preferred_element_type=jnp.float32).astype(jnp.int32) - 1
+    vals = jnp.where(keep, best, 0).astype(jnp.float32)
     # scatter-free compaction: each kept frame lands at its unique pos, so
-    # a broadcast-compare + sum reproduces the scatter-max collapse exactly
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n_frames), 2)
-    onehot = (pos[:, :, None] == iota) & keep[:, :, None]
-    tokens = jnp.sum(jnp.where(onehot, best[:, :, None], 0), axis=1)
+    # tokens[p] = sum_j vals[j] * [pos[j] == p] reproduces the collapse
+    for lane in range(block_l):
+        onehot = (r == pos[lane:lane + 1, :]).astype(jnp.float32)  # (p, j)
+        tokens_ref[lane:lane + 1, :] = jax.lax.dot_general(
+            vals[lane:lane + 1, :], onehot, nt,
+            preferred_element_type=jnp.float32).astype(jnp.int32)
 
     # ------------------------------------------------- counter epilogue --
-    tokens_ref[...] = tokens
     lens_ref[...] = lens
     prev_out_ref[...] = best[:, n_frames - 1:]
     bases_out_ref[...] = jnp.where(rmask, 0, bases_ref[...]) + lens
@@ -302,48 +360,64 @@ def _fused_pallas(rows, pads, reset, prev, bases, ticks, conv, params, *,
 
     # ---- static per-layer plan + flat operand list -----------------------
     any_int8 = False
-    meta, operands, in_specs = [], [], []
+    meta, operands, in_specs, scratch = [], [], [], []
 
     def add(arr, spec):
         operands.append(arr)
         in_specs.append(spec)
 
-    add(_pad_lanes(rows, lanes_pad),
-        pl.BlockSpec((bl, chunk), lambda i: (i, 0)))
+    def lane_spec(shape):
+        return pl.BlockSpec((bl,) + shape,
+                            lambda i: (i,) + (0,) * len(shape))
+
+    def whole(shape):
+        return pl.BlockSpec(shape, lambda i: (0,) * len(shape))
+
+    add(_pad_lanes(rows, lanes_pad)[..., None], lane_spec((chunk, 1)))
     # padding lanes are all-padding frames: BLANK everywhere, lens 0
-    add(_pad_lanes(pads, lanes_pad, fill=1.0),
-        pl.BlockSpec((bl, n_frames), lambda i: (i, 0)))
-    for a in (reset.astype(jnp.float32), prev, bases, ticks):
-        add(_pad_lanes(a.reshape(lanes, 1), lanes_pad),
-            pl.BlockSpec((bl, 1), lambda i: (i, 0)))
+    add(_pad_lanes(pads, lanes_pad, fill=1.0), lane_spec((n_frames,)))
+    reset = _pad_lanes(reset.astype(jnp.float32), lanes_pad)
+    add(reset.reshape(lanes_pad, 1), lane_spec((1,)))
+    add(reset.reshape(lanes_pad, 1, 1), lane_spec((1, 1)))
+    for a in (prev, bases, ticks):
+        add(_pad_lanes(a.reshape(lanes, 1), lanes_pad), lane_spec((1,)))
+    t = chunk
     for i, sp in enumerate(specs):
         p = params[sp.name]
         w = p["w"]
         quantized = qcore.is_quantized(w)
         any_int8 = any_int8 or quantized
+        is_last = i == len(specs) - 1
+        staged = sp.ksize > 1 or sp.stride > 1    # carry_rows = K - stride
         meta.append({"i": i, "ksize": sp.ksize, "stride": sp.stride,
                      "carry_rows": sp.carry_rows, "cout": sp.cout,
-                     "activation": sp.activation, "quantized": quantized})
+                     "activation": sp.activation, "quantized": quantized,
+                     "staged": staged})
         if sp.carry_rows:
             add(_pad_lanes(conv[i], lanes_pad),
-                pl.BlockSpec((bl, sp.carry_rows, sp.cin),
-                             lambda i: (i, 0, 0)))
-        wspec = pl.BlockSpec((sp.ksize, sp.cin, sp.cout),
-                             lambda i: (0, 0, 0))
-        vspec = pl.BlockSpec((1, sp.cout), lambda i: (0, 0))
+                lane_spec((sp.carry_rows, sp.cin)))
+        if staged:
+            scratch.append(pltpu.VMEM((bl, sp.carry_rows + t, sp.cin),
+                                      jnp.float32))
+        t //= sp.stride
+        # the last layer runs transposed: (K, Cout, Cin) weights and
+        # per-channel vectors as (Cout, 1) columns
+        vshape = (sp.cout, 1) if is_last else (1, sp.cout)
+        wq = w.q if quantized else w
+        if is_last:
+            wq = jnp.swapaxes(wq, 1, 2)
+        add(wq, whole(wq.shape))
         if quantized:
             # combined dequant scale (sa*sw) and the act scale, precomputed
             # outside — the same f32 products the unfused epilogue forms
             sa = jnp.asarray(w.act_scale, jnp.float32)
             sw = jnp.asarray(w.scale, jnp.float32)
-            add(w.q, wspec)
-            add(jnp.broadcast_to(sa * sw, (sp.cout,)).reshape(1, sp.cout),
-                vspec)
-            add(p["b"].reshape(1, sp.cout), vspec)
-            add(sa.reshape(1, 1), pl.BlockSpec((1, 1), lambda i: (0, 0)))
-        else:
-            add(w, wspec)
-            add(p["b"].reshape(1, sp.cout), vspec)
+            add(jnp.broadcast_to(sa * sw, (sp.cout,)).reshape(vshape),
+                whole(vshape))
+        add(p["b"].reshape(vshape), whole(vshape))
+        if quantized:
+            add(sa.reshape(1, 1), whole((1, 1)))
+    scratch.append(pltpu.VMEM((bl, n_frames), jnp.int32))   # per-lane best
 
     if any_int8:
         fabric.record("fabric.precision.fused_stream.int8")
@@ -356,19 +430,12 @@ def _fused_pallas(rows, pads, reset, prev, bases, ticks, conv, params, *,
         jax.ShapeDtypeStruct((lanes_pad, 1), jnp.int32),          # bases
         jax.ShapeDtypeStruct((lanes_pad, 1), jnp.int32),          # ticks
     ]
-    out_specs = [
-        pl.BlockSpec((bl, n_frames), lambda i: (i, 0)),
-        pl.BlockSpec((bl, 1), lambda i: (i, 0)),
-        pl.BlockSpec((bl, 1), lambda i: (i, 0)),
-        pl.BlockSpec((bl, 1), lambda i: (i, 0)),
-        pl.BlockSpec((bl, 1), lambda i: (i, 0)),
-    ]
+    out_specs = [lane_spec((n_frames,))] + [lane_spec((1,))] * 4
     for sp in specs:
         if sp.carry_rows:
             out_shapes.append(jax.ShapeDtypeStruct(
                 (lanes_pad, sp.carry_rows, sp.cin), cfg.dtype))
-            out_specs.append(pl.BlockSpec((bl, sp.carry_rows, sp.cin),
-                                          lambda i: (i, 0, 0)))
+            out_specs.append(lane_spec((sp.carry_rows, sp.cin)))
 
     kernel = functools.partial(_fused_kernel_entry, meta=tuple(
         tuple(sorted(m.items())) for m in meta), block_l=bl, chunk=chunk,
@@ -379,7 +446,8 @@ def _fused_pallas(rows, pads, reset, prev, bases, ticks, conv, params, *,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
-        compiler_params=compat.CompilerParams(
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*operands)
@@ -428,12 +496,6 @@ def _fused_supported(args, kwargs, tune):
                 return False, "int8_axis"
         elif precisions[i] == "int8":
             return False, "precision_policy"
-    if jax.default_backend() == "tpu":
-        # compiled lowering needs lane-tile-aligned channel widths; the
-        # interpret target (CPU parity path) has no such floor
-        if any(sp.cout % 128 or (sp.cin % 128 and sp.cin != cfg.in_channels)
-               for sp in _specs(cfg)):
-            return False, "tpu_channel_align"
     return True, ""
 
 

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
-
 
 def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, s_ref, *, chunk: int):
     ci = pl.program_id(1)
@@ -97,7 +95,7 @@ def ssd_scan(
         out_specs=pl.BlockSpec((1, chunk, dh), lambda h, i: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t, dh), x.dtype),
         scratch_shapes=[pltpu.VMEM((ds, dh), jnp.float32)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -14,13 +14,8 @@ import jax
 
 
 def _make_mesh(shape, axes) -> jax.sharding.Mesh:
-    # jax.sharding.AxisType only exists on jax >= 0.5; older versions default
-    # every axis to Auto, which is exactly what we ask for anyway.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -234,6 +234,8 @@ def main() -> None:
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
                     help="capture a jax.profiler trace around the run")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.list_workloads:
         for w in engine_api.workloads():

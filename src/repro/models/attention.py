@@ -272,9 +272,8 @@ def _seq_parallel_decode_attn(q, kc, vc, pos, cfg: ModelConfig, mesh,
     in_specs = (P(batch_spec), P(batch_spec, seq_spec),
                 P(batch_spec, seq_spec), P(batch_spec))
     out_specs = P(batch_spec)
-    from repro.distributed.sharding import shard_map_compat
-    mapped = shard_map_compat(local, mesh, in_specs=in_specs,
-                              out_specs=out_specs)
+    mapped = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     return mapped(q, kc, vc, pos)
 
 

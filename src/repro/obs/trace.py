@@ -277,27 +277,20 @@ def as_tracer(value) -> Tracer:
 
 @contextlib.contextmanager
 def jax_profile_window(logdir: str | None, enabled: bool = True):
-    """Optionally capture a ``jax.profiler`` device trace around a window
-    of the run (``logdir=None`` or a failed profiler start degrade to a
-    no-op — device-side tracing is best-effort on every backend)."""
+    """Capture a ``jax.profiler`` device trace around a window of the run.
+
+    ``logdir=None`` (or ``enabled=False``) is a no-op that yields False.  A
+    trace that was asked for and cannot start or stop raises: a run whose
+    trace is missing must not look like a traced run."""
     if not enabled or logdir is None:
         yield False
         return
-    started = False
+    import jax
+    jax.profiler.start_trace(logdir)
     try:
-        import jax
-        jax.profiler.start_trace(logdir)
-        started = True
-    except Exception:
-        pass
-    try:
-        yield started
+        yield True
     finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+        jax.profiler.stop_trace()
 
 
 # ---------------------------------------------------------- validation ----

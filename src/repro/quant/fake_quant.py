@@ -40,9 +40,7 @@ def fake_quant_params(params, *, weight_keys: frozenset = DEFAULT_WEIGHT_KEYS,
     """Fake-quantize the same weight leaves ``quantize_params`` would
     quantize for real, leaving everything else (biases, norms) untouched —
     so QAT optimizes exactly the deployment numerics."""
-    flatten_with_path = getattr(jax.tree, "flatten_with_path",
-                                jax.tree_util.tree_flatten_with_path)
-    flat, treedef = flatten_with_path(params, is_leaf=is_quantized)
+    flat, treedef = jax.tree.flatten_with_path(params, is_leaf=is_quantized)
     out = []
     for path, leaf in flat:
         names = [_key_name(p) for p in path]

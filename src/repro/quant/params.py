@@ -110,11 +110,9 @@ def quantize_params(params, calib: Optional[Calibration] = None, *,
     Biases and every other leaf pass through unchanged; the result is a
     pytree of the same structure, usable anywhere the float params were.
     """
-    flatten_with_path = getattr(jax.tree, "flatten_with_path",
-                                jax.tree_util.tree_flatten_with_path)
     # already-quantized leaves are opaque (idempotent pass-through), not
     # pytrees to descend into
-    flat, treedef = flatten_with_path(params, is_leaf=is_quantized)
+    flat, treedef = jax.tree.flatten_with_path(params, is_leaf=is_quantized)
     out = []
     for path, leaf in flat:
         names = [_key_name(p) for p in path]
@@ -158,10 +156,8 @@ def params_precision(params) -> str:
 
 def quantized_fraction(params) -> float:
     """Fraction of parameter scalars stored as int8 (reporting helper)."""
-    flatten_with_path = getattr(jax.tree, "flatten_with_path",
-                                jax.tree_util.tree_flatten_with_path)
     total = q = 0
-    for _, leaf in flatten_with_path(params, is_leaf=is_quantized)[0]:
+    for _, leaf in jax.tree.flatten_with_path(params, is_leaf=is_quantized)[0]:
         n = int(np.prod(leaf.shape))
         total += n
         if is_quantized(leaf):

@@ -140,13 +140,16 @@ def build_step_fn(cfg: bc.BasecallerConfig, fabric: fabric_mod.FabricPolicy,
         in_specs_tail = 3
 
     if mesh is not None:
-        from repro.distributed.sharding import LANE_AXIS, shard_map_compat
+        from repro.distributed.sharding import LANE_AXIS
         lane_p = P(LANE_AXIS)
         # pytree-prefix specs: one P() replicates the whole params tree, one
-        # lane spec shards every lane-major leaf of the state tree
-        step = shard_map_compat(step, mesh,
-                                in_specs=(P(),) + (lane_p,) * in_specs_tail,
-                                out_specs=(lane_p, lane_p, lane_p))
+        # lane spec shards every lane-major leaf of the state tree.  The
+        # Pallas kernels' output shapes carry no varying-axes annotation,
+        # so the checker is off.
+        step = jax.shard_map(step, mesh=mesh,
+                             in_specs=(P(),) + (lane_p,) * in_specs_tail,
+                             out_specs=(lane_p, lane_p, lane_p),
+                             check_vma=False)
     return jax.jit(step)
 
 
@@ -193,6 +196,14 @@ class AdaptiveSamplingRuntime:
         self.fused = bool(fused)
         self._step = build_step_fn(cfg, self.fabric, mesh, fused=self.fused)
         self.lane_state = init_lane_state(cfg, channels)
+        if mesh is not None:
+            # lane-sharded from the start: the step never sees (and never
+            # compiles for) state parked on one device
+            from jax.sharding import NamedSharding
+
+            from repro.distributed.sharding import LANE_AXIS
+            self.lane_state = jax.device_put(
+                self.lane_state, NamedSharding(mesh, P(LANE_AXIS)))
         self.records: list[ReadRecord] = []
         self.telemetry = Telemetry(workload="adaptive_sampling",
                                    tracer=tracer)

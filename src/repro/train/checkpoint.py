@@ -59,10 +59,7 @@ def _from_storable(arr: np.ndarray, dtype_name: str) -> np.ndarray:
 
 
 def _flatten(tree) -> tuple[list[tuple[str, Any]], Any]:
-    # jax.tree.flatten_with_path only exists on jax >= 0.5
-    flatten_with_path = getattr(jax.tree, "flatten_with_path",
-                                jax.tree_util.tree_flatten_with_path)
-    flat, treedef = flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     items = []
     for path, leaf in flat:
         key = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))

@@ -33,10 +33,7 @@ def test_scan_weighted_equals_unrolled():
     assert costs["scan"].flops == want
     assert costs["unroll"].flops == want
     # built-in cost_analysis undercounts the scan (the bug we fix)
-    builtin = jax.jit(scanned).lower(w, x).compile().cost_analysis()
-    if isinstance(builtin, (list, tuple)):  # jax < 0.5
-        builtin = builtin[0]
-    builtin = builtin["flops"]
+    builtin = jax.jit(scanned).lower(w, x).compile().cost_analysis()["flops"]
     assert builtin < want / 4
 
 

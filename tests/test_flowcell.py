@@ -298,7 +298,9 @@ def test_mesh_invariance_two_devices():
     here = os.path.dirname(__file__)
     src = os.path.abspath(os.path.join(here, "..", "src"))
     script = _MESH_SCRIPT.format(src=src, tests=os.path.abspath(here))
+    # the child builds virtual CPU devices: never let it reach for a chip
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", script],
                           capture_output=True, text=True, timeout=600,
                           env=env)

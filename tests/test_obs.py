@@ -20,6 +20,7 @@ Pinned behaviours:
 import io
 import json
 
+import jax
 import numpy as np
 import pytest
 
@@ -28,8 +29,8 @@ from optional_hypothesis import given, settings, st
 from repro.engine.telemetry import Telemetry
 from repro.kernels import fabric as fabric_mod
 from repro.obs import (Counters, Gauges, LogHistogram, NULL_TRACER, Tracer,
-                       TimeSeriesExporter, as_tracer, validate_chrome_trace,
-                       weighted_percentile)
+                       TimeSeriesExporter, as_tracer, jax_profile_window,
+                       validate_chrome_trace, weighted_percentile)
 from repro.obs.export import validate_timeseries
 from repro.obs.trace import _NULL_SPAN, read_spans
 
@@ -399,3 +400,34 @@ class TestTimeSeriesExporter:
         exp.emit()
         (line,) = buf.getvalue().splitlines()
         assert json.loads(line)["bases_per_s"] == pytest.approx(10.0)
+
+
+class TestJaxProfileWindow:
+    def test_no_logdir_is_a_noop(self, monkeypatch):
+        def boom(*_):
+            raise AssertionError("profiler must not start")
+        monkeypatch.setattr(jax.profiler, "start_trace", boom)
+        with jax_profile_window(None) as started:
+            assert started is False
+        with jax_profile_window("unused", enabled=False) as started:
+            assert started is False
+
+    def test_failed_start_raises(self, monkeypatch, tmp_path):
+        def fail(*_):
+            raise RuntimeError("no profiler here")
+        monkeypatch.setattr(jax.profiler, "start_trace", fail)
+        with pytest.raises(RuntimeError, match="no profiler"):
+            with jax_profile_window(str(tmp_path)):
+                pass
+
+    def test_failed_stop_raises(self, monkeypatch, tmp_path):
+        calls = []
+        monkeypatch.setattr(jax.profiler, "start_trace", calls.append)
+
+        def fail():
+            raise RuntimeError("trace lost")
+        monkeypatch.setattr(jax.profiler, "stop_trace", fail)
+        with pytest.raises(RuntimeError, match="trace lost"):
+            with jax_profile_window(str(tmp_path)) as started:
+                assert started is True
+        assert calls == [str(tmp_path)]

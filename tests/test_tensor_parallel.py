@@ -40,7 +40,9 @@ SCRIPTS = os.path.abspath(os.path.join(HERE, "..", "scripts"))
 
 def _run_twodev(script: str) -> dict:
     """Run a snippet under 2 virtual CPU devices, return its RESULT json."""
+    # the child builds virtual CPU devices: never let it reach for a chip
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", script],
                           capture_output=True, text=True, timeout=600,
                           env=env)
@@ -543,9 +545,9 @@ def local_loss(p, b):
     with tp.axis_ctx("model", 2):
         return transformer.loss_fn(p, b, cfg)
 
-f = jax.jit(shardlib.shard_map_compat(
-    local_loss, mesh, in_specs=(tp.param_pspecs(plan, tparams), P()),
-    out_specs=(P(), P())))
+f = jax.jit(jax.shard_map(
+    local_loss, mesh=mesh, in_specs=(tp.param_pspecs(plan, tparams), P()),
+    out_specs=(P(), P()), check_vma=False))
 loss_tp, _ = f(tparams, batch)
 print("RESULT " + json.dumps(
     {{"ref": float(loss_ref), "tp": float(loss_tp)}}))
