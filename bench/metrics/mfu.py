@@ -1,0 +1,7 @@
+"""Conv FLOPs of the read samples basecalled in the window, each once,
+over the window's seconds times the chips' bf16 peak."""
+from bench.lib.readers import mfu_pct
+
+
+def read(obs):
+    return mfu_pct(obs, obs.get("samples"))
