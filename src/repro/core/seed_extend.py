@@ -21,6 +21,7 @@ extension queues.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 
 import jax
@@ -51,6 +52,8 @@ class AlignmentResult:
     scores: np.ndarray      # (R,) banded NW score
     mapq: np.ndarray        # (R,) score gap to runner-up (proxy)
     accepted: np.ndarray    # (R,) bool
+    voted: np.ndarray       # (R,) bool — at least one seed hit (row voted)
+    upload_bytes: int       # index bytes sent to the device for this call
 
 
 def _extract_seeds(reads: jnp.ndarray, cfg: AlignConfig):
@@ -88,54 +91,68 @@ def _vote_candidates(hits: np.ndarray, offsets: np.ndarray, genome_len: int,
 
 def align_reads(index: fm_index.FMIndex, genome: np.ndarray,
                 reads: np.ndarray, cfg: AlignConfig = AlignConfig(),
-                *, interpret=fabric_mod.UNSET, fabric=None) -> AlignmentResult:
+                *, interpret=fabric_mod.UNSET, fabric=None,
+                stage=contextlib.nullcontext) -> AlignmentResult:
     """Align a batch of reads against ``genome`` (1..4 tokens).
 
     The banded-extension placement (ED kernel vs oracle) comes from the
     compute-fabric policy; ``interpret=`` is a deprecated shim.
+
+    ``stage`` is a context-manager factory called with each phase's name
+    (``map.upload``, ``map.search``, ``map.vote``, ``map.extend``, in that
+    order and covering the call), e.g. ``Telemetry.stage``; the default
+    names nothing.  No phase waits on the device to close: a transfer
+    still in flight finishes inside the phase that reads its result.
     """
     fabric = fabric_mod.legacy_policy("seed_extend.align_reads",
                                       interpret=interpret, fabric=fabric)
-    reads_j = jnp.asarray(reads)
     r, l = reads.shape
-    seeds, offsets = _extract_seeds(reads_j, cfg)
-    s = seeds.shape[1]
-    arrays = index.device_arrays()
-    _, pos = fm_index.backward_search(
-        arrays, seeds.reshape(r * s, cfg.seed_len),
-        max_hits=cfg.max_hits_per_seed)
-    hits = np.asarray(pos).reshape(r, s, cfg.max_hits_per_seed)
-    cands = _vote_candidates(hits, offsets, index.length, cfg)
+    with stage("map.upload"):
+        arrays = index.device_arrays()
+        upload_bytes = sum(int(a.nbytes) for a in arrays.values())
+    with stage("map.search"):
+        seeds, offsets = _extract_seeds(jnp.asarray(reads), cfg)
+        s = seeds.shape[1]
+        _, pos = fm_index.backward_search(
+            arrays, seeds.reshape(r * s, cfg.seed_len),
+            max_hits=cfg.max_hits_per_seed)
+        hits = np.asarray(pos).reshape(r, s, cfg.max_hits_per_seed)
+    with stage("map.vote"):
+        voted = (hits >= 0).reshape(r, -1).any(axis=1)
+        cands = _vote_candidates(hits, offsets, index.length, cfg)
+    with stage("map.extend"):
+        # window extraction (host gather; read length + band slack)
+        wlen = l + 2 * cfg.band
+        gpad = np.concatenate([
+            np.zeros(cfg.band, np.int32), np.asarray(genome, np.int32),
+            np.zeros(wlen, np.int32)])  # zeros mismatch every base
+        win_idx = (np.clip(cands, 0, None)[..., None]
+                   + np.arange(wlen)[None, None, :])
+        windows = gpad[win_idx]  # (R, C, wlen); cand -1 -> leading pad
 
-    # window extraction (host gather; windows are read-length + band slack)
-    wlen = l + 2 * cfg.band
-    gpad = np.concatenate([
-        np.zeros(cfg.band, np.int32), np.asarray(genome, np.int32),
-        np.zeros(wlen, np.int32)])  # zeros mismatch every base
-    win_idx = np.clip(cands, 0, None)[..., None] + np.arange(wlen)[None, None, :]
-    windows = gpad[win_idx]  # (R, C, wlen); cand -1 -> window of leading pad
+        # banded extension on the ED engine: read vs each candidate window
+        q = jnp.asarray(np.repeat(reads, cfg.max_candidates, axis=0))
+        t = jnp.asarray(windows.reshape(r * cfg.max_candidates, wlen))
+        scores = ops.banded_align(
+            q, t, band=2 * cfg.band, match=cfg.match,
+            mismatch=cfg.mismatch, gap=cfg.gap, local=True, fabric=fabric)
+        scores = np.asarray(scores).reshape(r, cfg.max_candidates)
+        scores = np.where(cands >= 0, scores, -(10 ** 9))
 
-    # banded extension on the ED engine: query=read vs each candidate window
-    q = jnp.asarray(np.repeat(reads, cfg.max_candidates, axis=0))
-    t = jnp.asarray(windows.reshape(r * cfg.max_candidates, wlen))
-    scores = ops.banded_align(
-        q, t, band=2 * cfg.band, match=cfg.match, mismatch=cfg.mismatch,
-        gap=cfg.gap, local=True, fabric=fabric)
-    scores = np.asarray(scores).reshape(r, cfg.max_candidates)
-    scores = np.where(cands >= 0, scores, -(10 ** 9))
-
-    best = np.argmax(scores, axis=1)
-    best_score = scores[np.arange(r), best]
-    sorted_sc = np.sort(scores, axis=1)
-    gap2 = best_score - (sorted_sc[:, -2] if cfg.max_candidates > 1
-                         else np.zeros(r))
-    positions = cands[np.arange(r), best]
-    max_possible = cfg.match * l
-    accepted = (best_score > cfg.min_score_frac * max_possible)
-    positions = np.where(accepted, positions, -1)
+        best = np.argmax(scores, axis=1)
+        best_score = scores[np.arange(r), best]
+        sorted_sc = np.sort(scores, axis=1)
+        gap2 = best_score - (sorted_sc[:, -2] if cfg.max_candidates > 1
+                             else np.zeros(r))
+        positions = cands[np.arange(r), best]
+        max_possible = cfg.match * l
+        accepted = (best_score > cfg.min_score_frac * max_possible)
+        positions = np.where(accepted, positions, -1)
     return AlignmentResult(
         positions=positions,
         scores=best_score,
         mapq=np.clip(gap2, 0, 60),
         accepted=accepted,
+        voted=voted,
+        upload_bytes=upload_bytes,
     )

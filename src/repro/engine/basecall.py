@@ -75,12 +75,13 @@ class BasecallEngine(EngineBase):
         self.telemetry.observe_latency(dt, weight=len(chunk_rows))
         self.telemetry.dispatches += 1
         self.telemetry.steps += 1
-        for j, (slot, _) in enumerate(admitted):
-            ln = int(lens[j])
-            self.reads.append(np.asarray(tokens[j][:ln]))
-            self.telemetry.bases += ln
-            self.telemetry.completed += 1
-            self.scheduler.release(slot)
+        with self.telemetry.stage("readback"):
+            for j, (slot, _) in enumerate(admitted):
+                ln = int(lens[j])
+                self.reads.append(np.asarray(tokens[j][:ln]))
+                self.telemetry.bases += ln
+                self.telemetry.completed += 1
+                self.scheduler.release(slot)
         self.telemetry.samples += int(chunk_rows.size)
         self.telemetry.wall_s += time.perf_counter() - t_wall
         self.telemetry.gauge("queue_depth", self.scheduler.pending)

@@ -29,6 +29,8 @@ from __future__ import annotations
 import contextlib
 import time
 
+import jax
+
 from repro.kernels import fabric as _fabric
 from repro.obs.metrics import (Counters, Gauges, LogHistogram,
                                weighted_percentile)
@@ -142,10 +144,17 @@ class Telemetry:
     def stage(self, name: str):
         """Accumulate wall time of a pipeline stage: ``with tel.stage("map")``
         — and record it as an X span on the engine's host track when a
-        tracer is attached."""
+        tracer is attached.
+
+        The block also runs under ``jax.profiler.TraceAnnotation(
+        "stage.<name>")``, so any profiler trace (the benchmark's, an
+        operator's ``--profile-dir``) carries the stage on the device
+        trace's clock.  Stages nest with dotted names (``map.upload``
+        inside ``map``); ``stage_s`` keeps each name's inclusive time."""
         t0 = time.perf_counter()
         try:
-            yield
+            with jax.profiler.TraceAnnotation(f"stage.{name}"):
+                yield
         finally:
             dur = time.perf_counter() - t0
             self.stage_s[name] = self.stage_s.get(name, 0.0) + dur

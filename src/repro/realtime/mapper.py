@@ -9,6 +9,7 @@ compile exactly once for the lifetime of a run.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 
 import numpy as np
@@ -48,6 +49,8 @@ class MapResult:
     positions: np.ndarray   # (R,) int  — best reference start (-1 unmapped)
     mapq: np.ndarray        # (R,) float — score gap to runner-up (0..60)
     scores: np.ndarray      # (R,) int  — banded-SW score of the best hit
+    voted: np.ndarray       # (R,) bool — at least one seed hit (row voted)
+    upload_bytes: int       # index bytes sent to the device for this call
 
 
 # Prefixes are short (~50 bases) and noisy: denser/shorter seeds than the
@@ -72,15 +75,20 @@ class PrefixMapper:
                                                 interpret=interpret,
                                                 fabric=fabric)
 
-    def map_prefixes(self, prefixes: np.ndarray) -> MapResult:
+    def map_prefixes(self, prefixes: np.ndarray, *,
+                     stage=contextlib.nullcontext) -> MapResult:
         """prefixes: (R, L) called bases (1..4; 0-padded rows are ignored by
         the caller).  R and L must stay constant across calls so the jitted
-        kernels compile once."""
+        kernels compile once.  ``stage`` names the alignment's phases
+        (``map.upload`` ... ``map.extend``, see
+        :func:`seed_extend.align_reads`)."""
         res = seed_extend.align_reads(self.index, self.panel.reference,
                                       np.asarray(prefixes, np.int32),
-                                      self.cfg, fabric=self._fabric)
+                                      self.cfg, fabric=self._fabric,
+                                      stage=stage)
         pos = np.clip(res.positions, 0, len(self.panel.reference) - 1)
         on_target = np.where(res.accepted, self.panel.target_mask[pos], False)
         return MapResult(mapped=res.accepted, on_target=on_target,
                          positions=res.positions, mapq=res.mapq,
-                         scores=res.scores)
+                         scores=res.scores, voted=res.voted,
+                         upload_bytes=res.upload_bytes)

@@ -403,7 +403,7 @@ class AdaptiveSamplingRuntime:
         """
         tel = self.telemetry
         sessions = p["sessions"]
-        with tel.scope(), tel.stage("basecall"):
+        with tel.scope(), tel.stage("basecall"), tel.stage("basecall.wait"):
             # blocks on the device step dispatched when p was created
             tokens_np = np.asarray(p["tokens"])
             lens_np = np.asarray(p["lens"])
@@ -441,10 +441,13 @@ class AdaptiveSamplingRuntime:
                 prefixes[b, :len(window)] = window
                 prefix_lens[b] = int(bases_np[b])
             with tel.scope(), tel.stage("map"):
-                res = self.mapper.map_prefixes(prefixes)
+                res = self.mapper.map_prefixes(prefixes, stage=tel.stage)
                 decisions, reasons = policy_mod.decide(
                     res.mapped, res.on_target, res.mapq, prefix_lens,
                     self.policy)
+            tel.count("map.rows_wanted", len(cand))
+            tel.count("map.rows_voted", int(res.voted.sum()))
+            tel.count("map.upload_bytes", res.upload_bytes)
             now = time.perf_counter()
             for b in cand:
                 if decisions[b] is not Decision.WAIT:

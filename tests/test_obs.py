@@ -316,6 +316,45 @@ class TestTracer:
         assert xs[0]["dur"] >= 0
         assert tel.stage_s["map"] >= 0
 
+    def test_nested_stages_keep_inclusive_time(self):
+        tel = Telemetry("w", tracer=True)
+        with tel.stage("map"):
+            with tel.stage("map.upload"):
+                pass
+            with tel.stage("map.vote"):
+                pass
+        st = tel.stage_s
+        assert st["map.upload"] + st["map.vote"] <= st["map"]
+        xs = [e for e in tel.tracer.to_chrome()["traceEvents"]
+              if e.get("ph") == "X"]
+        assert [e["name"] for e in xs] == ["map", "map.upload", "map.vote"]
+        assert validate_chrome_trace(tel.tracer.to_chrome()) == []
+
+    def test_stage_opens_a_profiler_annotation(self, monkeypatch):
+        """Every stage runs under ``TraceAnnotation("stage.<name>")``, so
+        any profiler trace carries it, with or without a Tracer."""
+        opened = []
+
+        class Recording:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                opened.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                opened.append(("exit", self.name))
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recording)
+        tel = Telemetry("w")
+        with tel.stage("basecall"):
+            with tel.stage("basecall.wait"):
+                pass
+        assert opened == [("enter", "stage.basecall"),
+                          ("enter", "stage.basecall.wait"),
+                          ("exit", "stage.basecall.wait"),
+                          ("exit", "stage.basecall")]
+
     def test_duplicate_process_labels_disambiguate(self):
         t = Tracer()
         assert t.pid("basecall") != t.pid("basecall")
@@ -350,6 +389,22 @@ class TestEngineTraceEndToEnd:
         # stage spans + scheduler instants landed on the same process
         phases = {e["ph"] for e in doc["traceEvents"]}
         assert {"B", "E", "X", "i", "C", "M"} <= phases
+
+    def test_offline_step_times_its_readback(self):
+        """``BasecallEngine.step`` reads its rows back inside one
+        ``readback`` stage a batch, within the step's wall time."""
+        import time
+
+        import repro.engine as engine_api
+        eng = engine_api.build("basecall", preset="smoke", seed=0)
+        eng.submit(np.zeros((4, 512), np.float32))
+        t0 = time.perf_counter()
+        assert eng.step()
+        wall = time.perf_counter() - t0
+        st = eng.telemetry.stage_s
+        assert 0 < st["readback"] <= wall
+        assert st["readback"] + st["basecall"] + st["decode"] <= wall
+        assert len(eng.reads) == 4
 
     def test_untraced_engine_emits_zero_events(self):
         import repro.engine as engine_api

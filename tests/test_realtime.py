@@ -1,4 +1,8 @@
 """Adaptive-sampling runtime: streaming equivalence, policy, end-to-end."""
+import contextlib
+import pathlib
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -168,6 +172,25 @@ class TestPrefixMapper:
         for s, p in zip(starts, res.positions):
             assert abs(int(p) - s) <= 16
 
+    def test_phases_cover_the_call_in_order(self, rng):
+        """The four phases open in order, one each per call, and the result
+        says which rows voted and how many index bytes went up."""
+        ref = G.random_genome(rng, 6_000)
+        mapper = PrefixMapper(TargetPanel.build(ref, [(0, 3_000)]))
+        prefixes = np.stack([ref[s: s + 48] for s in (100, 2_000)])
+        opened = []
+
+        def stage(name):
+            opened.append(name)
+            return contextlib.nullcontext()
+
+        res = mapper.map_prefixes(prefixes, stage=stage)
+        assert opened == ["map.upload", "map.search", "map.vote",
+                          "map.extend"]
+        assert res.voted.all() and res.mapped.all()
+        assert res.upload_bytes == sum(
+            a.nbytes for a in mapper.index.device_arrays().values())
+
 
 # ------------------------------------------------------------- runtime ----
 class TestRuntime:
@@ -242,6 +265,95 @@ class TestRuntime:
         decided = (report["accepted"] + report["ejected"]
                    + report["timeouts"])
         assert len(runtime.telemetry.latencies_ms) == decided
+
+
+# ------------------------------------------------ program's own spans ----
+class TestProgramSpans:
+    """The runtime names the mapper's phases and the device wait as child
+    stages, counts each map call's rows and index bytes, and opens the
+    stages on the profiler's clock by itself."""
+
+    MAP_PHASES = ("map.upload", "map.search", "map.vote", "map.extend")
+
+    def _runtime(self):
+        rng = np.random.default_rng(7)
+        cfg = bc.BasecallerConfig(kernels=(5, 3), channels=(16, 5),
+                                  strides=(1, 2))
+        params = bc.init(jax.random.key(0), cfg)
+        panel = TargetPanel.build(G.random_genome(rng, 4_000), [(0, 1_000)])
+        policy = PolicyConfig(min_prefix_bases=8, map_prefix_bases=24,
+                              max_prefix_bases=48,
+                              timeout_decision=Decision.ACCEPT,
+                              eject_latency_samples=32)
+        runtime = AdaptiveSamplingRuntime(
+            params, cfg, PrefixMapper(panel), policy, channels=4,
+            chunk_samples=128)
+        runtime.submit_all([
+            SimulatedRead(signal=rng.normal(size=600).astype(np.float32),
+                          read_id=i) for i in range(6)])
+        return runtime
+
+    def test_children_fit_inside_their_parents(self):
+        runtime = self._runtime()
+        runtime.run(max_ticks=200)
+        st = runtime.telemetry.stage_s
+        assert st["map"] > 0
+        for name in self.MAP_PHASES:
+            assert 0 < st[name] <= st["map"], name
+        assert sum(st[n] for n in self.MAP_PHASES) <= st["map"]
+        assert 0 < st["basecall.wait"] <= st["basecall"]
+
+    def test_map_counters_add_up(self):
+        runtime = self._runtime()
+        runtime.warmup()        # the warm-up map call is not counted
+        calls = []
+        inner = runtime.mapper.map_prefixes
+
+        def counted(prefixes, **kw):
+            calls.append(len(prefixes))
+            return inner(prefixes, **kw)
+        runtime.mapper.map_prefixes = counted
+        report = runtime.run(max_ticks=200)
+        c = runtime.telemetry.counters
+        rows = sum(calls)
+        assert calls and rows == len(calls) * runtime.channels
+        assert 0 < c["map.rows_wanted"] <= rows
+        assert 0 <= c["map.rows_voted"] <= rows
+        index_bytes = sum(
+            a.nbytes
+            for a in runtime.mapper.index.device_arrays().values())
+        assert c["map.upload_bytes"] == index_bytes * len(calls)
+        # the operator reads them in report()
+        assert report["map.upload_bytes"] == c["map.upload_bytes"]
+
+    def test_profiler_trace_holds_the_stages_unwrapped(self, tmp_path):
+        """A plain jax.profiler trace of one map-bearing tick carries the
+        program's stage spans: no benchmark wrapper is installed."""
+        sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+        from bench.lib import trace as trace_lib
+        runtime = self._runtime()
+        runtime.warmup()
+        tel = runtime.telemetry
+        assert "stage" not in vars(tel)     # Telemetry.stage itself
+        while "map" not in tel.stage_s:
+            assert runtime.tick()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            before = tel.stage_s["map"]
+            while tel.stage_s["map"] == before:
+                assert runtime.tick()
+        finally:
+            jax.profiler.stop_trace()
+        tr = trace_lib.load(trace_lib.find_xplane(str(tmp_path)))
+        names = {n for n, _, _ in tr.host}
+        assert {"stage.map", "stage.map.upload", "stage.map.search",
+                "stage.map.vote", "stage.map.extend", "stage.basecall",
+                "stage.basecall.wait"} <= names
+        spans = {n: (s, e) for n, s, e in tr.host}
+        lo, hi = spans["stage.map"]
+        for name in self.MAP_PHASES:
+            s, e = spans[f"stage.{name}"]
+            assert lo <= s <= e <= hi, name
 
 
 # --------------------------------------------------- lane recycling (CTC) --
