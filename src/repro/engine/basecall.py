@@ -76,9 +76,15 @@ class BasecallEngine(EngineBase):
         self.telemetry.dispatches += 1
         self.telemetry.steps += 1
         with self.telemetry.stage("readback"):
+            # one transfer a batch; rows are then cut on the host, so no
+            # device program depends on a row's token count
+            tok_h, lens_h = jax.device_get((tokens, lens))
+            self.telemetry.count("readback.bytes",
+                                 tok_h.nbytes + lens_h.nbytes)
             for j, (slot, _) in enumerate(admitted):
-                ln = int(lens[j])
-                self.reads.append(np.asarray(tokens[j][:ln]))
+                ln = int(lens_h[j])
+                # a copy, so a kept read does not pin the whole batch buffer
+                self.reads.append(tok_h[j, :ln].copy())
                 self.telemetry.bases += ln
                 self.telemetry.completed += 1
                 self.scheduler.release(slot)

@@ -247,6 +247,76 @@ class TestDeprecationShims:
                              batch=4, chunk=512)
 
 
+# ------------------------------------------------- basecall readback ----
+def _ragged_rows(rng, noise_lens, chunk=512):
+    """Rows of noise over their first ``n`` samples and zeros after, so
+    that they decode to different token counts."""
+    rows = np.zeros((len(noise_lens), chunk), np.float32)
+    for i, n in enumerate(noise_lens):
+        rows[i, :n] = rng.normal(size=n)
+    return rows
+
+
+def _direct_decode(eng, rows):
+    """Tokens and lengths of ``ctc.greedy_decode`` on the engine's logits."""
+    import jax.numpy as jnp
+    from repro.core import basecaller as bc, ctc
+    logits = bc.apply(eng.params, jnp.asarray(rows), cfg=eng.cfg,
+                      fabric=eng.fabric)
+    tokens, lens = ctc.greedy_decode(logits)
+    return np.asarray(tokens), np.asarray(lens)
+
+
+class TestBasecallReadback:
+    def test_reads_match_direct_decode(self):
+        cfg, params = _bc_setup()
+        eng = engine_api.build("basecall", params=params, cfg=cfg,
+                               batch=4, chunk=512)
+        rows = _ragged_rows(np.random.default_rng(0), [40, 120, 260, 512])
+        tokens, lens = _direct_decode(eng, rows)
+        assert len(set(lens.tolist())) == len(rows)
+        eng.submit(rows)
+        assert eng.step()
+        assert len(eng.reads) == len(rows)
+        for j, r in enumerate(eng.reads):
+            assert isinstance(r, np.ndarray) and r.dtype == np.int32
+            np.testing.assert_array_equal(r, tokens[j, :lens[j]])
+            # a kept read owns its memory rather than viewing the batch
+            assert r.base is None or r.flags.owndata
+        assert eng.telemetry.bases == int(lens.sum())
+        assert eng.telemetry.completed == len(rows)
+
+    def test_new_token_counts_compile_nothing(self):
+        cfg, params = _bc_setup()
+        eng = engine_api.build("basecall", params=params, cfg=cfg,
+                               batch=4, chunk=512)
+        rng = np.random.default_rng(1)
+        warm = _ragged_rows(rng, [40, 120, 260, 512])
+        fresh = _ragged_rows(rng, [80, 180, 340, 450])
+        tokens, warm_lens = _direct_decode(eng, warm)
+        _, fresh_lens = _direct_decode(eng, fresh)
+        assert not set(fresh_lens.tolist()) & set(warm_lens.tolist())
+        eng.submit(warm)
+        assert eng.step()
+        compiles = []
+
+        def on_duration(event, secs, **_):
+            if event == "/jax/core/compile/backend_compile_duration":
+                compiles.append(secs)
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        try:
+            eng.submit(fresh)
+            assert eng.step()
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on_duration)
+        assert compiles == []
+        assert [len(r) for r in eng.reads] == (
+            warm_lens.tolist() + fresh_lens.tolist())
+        assert eng.telemetry.counters["readback.bytes"] == 2 * (
+            tokens.nbytes + warm_lens.nbytes)
+
+
 @pytest.fixture(scope="module")
 def lm_smoke():
     from repro.configs import ARCHS
