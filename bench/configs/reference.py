@@ -1,13 +1,18 @@
-"""Plain reference of the configurations' conv-stack basecallers.
+"""Plain reference of the configurations' conv-stack basecallers, and the
+family's surface that ``bench.configs.model_of`` hands the harness.
 
 Straightforward ``jax.numpy`` in float32 at ``highest`` matmul precision:
 each conv layer is a sum over its taps of strided slices times the tap's
 weight matrix, ReLU between layers, no ReLU after the head, then greedy
-argmax and the CTC collapse in numpy.  It imports nothing of the program.
+argmax and the CTC collapse in numpy.  The reference imports nothing of
+the program; only ``offline_engine``, which builds the program under test,
+does.
 
 ``operands`` rounds every layer's inputs and weights before the product
 (``f32`` leaves them alone), which is how the control of ``correct`` is
 computed: the reference at a lower precision than the configuration's.
+
+The conv family's work is counted by ``bench/lib/cost.py``.
 """
 from __future__ import annotations
 
@@ -18,7 +23,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.lib import cost
+
 BLANK = 0
+WEIGHT_KINDS = ("he_normal", "step_levels")
+CONTROLS = ("bf16", "fp8", "int4")
+# the offline engine's jitted forward (``core.basecaller.apply``)
+FORWARD_MODULE = "jit__apply_jit"
 CALIBRATION_SAMPLES = 4104
 CALIBRATION_STEPS = 40
 
@@ -178,3 +189,40 @@ def collapse(classes: np.ndarray, valid: int) -> tuple[np.ndarray, np.ndarray]:
     prev = np.concatenate([[BLANK], best[:-1]])
     keep = (best != BLANK) & (best != prev)
     return best[keep].astype(np.int32), np.nonzero(keep)[0]
+
+
+# ------------------------------------------------- the family's surface ----
+def n_params(cfg: dict) -> int:
+    return cost.n_params(cfg)
+
+
+def macs_per_sample(cfg: dict) -> float:
+    return cost.macs_per_sample(cfg)
+
+
+def forward_work(cfg: dict, rows: int, samples: int) -> dict:
+    return cost.forward(cfg, rows, samples)
+
+
+def offline_engine(params, cfg: dict, *, batch: int, chunk: int, seed: int):
+    """The program's batch basecall engine for ``params``, from its normal
+    entry."""
+    from repro.core import basecaller as bc
+    from repro.engine import build
+
+    bc_cfg = bc.BasecallerConfig(kernels=tuple(cfg["kernels"]),
+                                 channels=tuple(cfg["channels"]),
+                                 strides=tuple(cfg["strides"]),
+                                 in_channels=cfg["in_channels"])
+    return build("basecall", params=params, cfg=bc_cfg, batch=batch,
+                 chunk=chunk, seed=seed)
+
+
+def offline_reads(params, cfg: dict, x: np.ndarray, *,
+                  operands: str = "f32") -> list[np.ndarray]:
+    """The reference's tokens of each whole row of ``x`` (centred padding),
+    at ``highest`` precision and in blocks of rows."""
+    with jax.default_matmul_precision("highest"):
+        classes = frame_classes(params, cfg, x, padding="same",
+                                operands=operands)
+    return [collapse(c, len(c))[0] for c in classes]

@@ -2,15 +2,17 @@
 
 Set-up cuts the mix's pool of long reads (fixed by its ``pool_seed``) into
 overlapping chunks, lays the pool out as whole batches in an order drawn
-from the seed, makes the weights on the device and
-builds ``repro.engine.build("basecall", batch=B, chunk=C)``.  Warm-up sends
+from the seed, and asks the configuration's model family
+(``bench.configs.model_of``) for the weights, made on the device, and the
+program's engine, ``repro.engine.build("basecall", batch=B, chunk=C)``
+for the conv stack.  Warm-up sends
 every batch of the pool through ``engine.step()`` once, so that every
 program the window will run is compiled.  The window then submits the
 batches in turn and calls ``engine.step()`` back to back.
 
 The check basecalls a seeded sample of the window's rows (with the row of
-most bases among them) again with the plain reference and compares the
-tokens the engine returned for them.
+most bases among them) again with the family's plain reference and
+compares the tokens the engine returned for them.
 """
 from __future__ import annotations
 
@@ -20,19 +22,16 @@ import time
 import jax
 import numpy as np
 
-from bench.configs import reference as ref
+from bench.configs import model_of
 from bench.lib import chunker, common
 
 
 def setup(run, cfg: dict, traffic: dict):
-    from repro.core import basecaller as bc
-    from repro.engine import build
-
+    model = model_of(cfg)
     b = traffic["batch"]
     with run.clock.phase("synthesis"):
-        # one pool of reads for every seed: the per-row readback compiles a
-        # program per token count, so a pool drawn from the seed would
-        # leave each run with programs of its own to compile in set-up
+        # one pool of reads for every seed, so that every seed basecalls
+        # the same work and only the order of its batches differs
         rows, counted = chunker.long_read_chunks(
             np.random.default_rng(traffic["pool_seed"]), traffic,
             cfg["signal"])
@@ -42,15 +41,11 @@ def setup(run, cfg: dict, traffic: dict):
         batches = [order[i * b:(i + 1) * b] for i in range(n_batches)]
         inputs = [np.ascontiguousarray(rows[idx]) for idx in batches]
     with run.clock.phase("weights"):
-        params = ref.make_params(cfg, run.seed)
+        params = model.make_params(cfg, run.seed)
         jax.block_until_ready(params)
     with run.clock.phase("build"):
-        bc_cfg = bc.BasecallerConfig(kernels=tuple(cfg["kernels"]),
-                                     channels=tuple(cfg["channels"]),
-                                     strides=tuple(cfg["strides"]),
-                                     in_channels=cfg["in_channels"])
-        eng = build("basecall", params=params, cfg=bc_cfg, batch=b,
-                    chunk=traffic["chunk"], seed=run.seed)
+        eng = model.offline_engine(params, cfg, batch=b,
+                                   chunk=traffic["chunk"], seed=run.seed)
     with run.clock.phase("warmup"):
         for x in inputs:
             eng.submit(x)
@@ -117,14 +112,11 @@ def check_rows(seed, reads: list, order: list, batches, rows, cfg: dict,
     pick = sorted(pick)
     row_ids = [int(batches[order[p // b]][p % b]) for p in pick]
     x = rows[row_ids]
-    params = ref.make_params(cfg, seed)
-    with jax.default_matmul_precision("highest"):
-        classes = ref.frame_classes(params, cfg, x, padding="same")
-        test = classes if operands == "f32" else ref.frame_classes(
-            params, cfg, x, padding="same", operands=operands)
-    want = [ref.collapse(c, len(c))[0] for c in classes]
+    model = model_of(cfg)
+    params = model.make_params(cfg, seed)
+    want = model.offline_reads(params, cfg, x, operands="f32")
     got = [np.asarray(reads[p]) for p in pick] if operands == "f32" else \
-        [ref.collapse(c, len(c))[0] for c in test]
+        model.offline_reads(params, cfg, x, operands=operands)
     rate, n_tok, n_diff = common.token_mismatch(got, want)
     limit = cfg["limits"]["offline"]["token_mismatch"]
     return {"attempted": len(pick), "failed": 0,

@@ -2,6 +2,7 @@
 run gave it nothing to read, and the metric is then left out."""
 from __future__ import annotations
 
+from bench.configs import model_of
 from bench.lib import cost
 from bench.lib import trace as trace_lib
 
@@ -45,5 +46,5 @@ def mfu_pct(obs: dict, samples: float):
     if not samples or obs["window_s"] <= 0:
         return None
     peak = cost.peaks(obs["device_kind"])["flops_per_s"]
-    flops = 2.0 * samples * cost.macs_per_sample(obs["cfg"])
+    flops = 2.0 * samples * model_of(obs["cfg"]).macs_per_sample(obs["cfg"])
     return flops / (obs["window_s"] * obs["chips_used"] * peak) * 100.0

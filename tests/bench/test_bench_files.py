@@ -31,15 +31,22 @@ def test_top_level_keys():
     assert 1 <= BENCH["run_seconds"] <= 51
 
 
+def check_config_file(root, data: dict):
+    """A configuration against its own model family's module: its count
+    of parameters, its weights kind and control, and drivers that exist."""
+    from bench.configs import model_of
+    model = model_of(data)
+    assert model.n_params(data) == data["params"]
+    drivers = {p.stem for p in (root / "bench/drivers").glob("*.py")}
+    assert set(data["limits"]) <= drivers
+    assert data["weights"]["kind"] in model.WEIGHT_KINDS
+    assert data["control"] in model.CONTROLS
+
+
 @pytest.mark.parametrize("cfg", BENCH["configs"], ids=lambda c: c["name"])
 def test_config_file_loads(cfg):
     assert NAME.match(cfg["name"])
-    data = json.loads((REPO / cfg["file"]).read_text())
-    from bench.lib import cost
-    assert cost.n_params(data) == data["params"]
-    assert set(data["limits"]) <= {"readuntil", "offline"}
-    assert data["weights"]["kind"] in ("he_normal", "step_levels")
-    assert data["control"] in ("bf16", "fp8", "int4")
+    check_config_file(REPO, json.loads((REPO / cfg["file"]).read_text()))
 
 
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda c: c["name"])
